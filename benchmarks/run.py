@@ -25,6 +25,8 @@ def main() -> None:
                          "one dataset/query per group")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.runtime.compile_cache import configure_jax_cache
+    configure_jax_cache()
 
     def want(name):
         return only is None or name in only

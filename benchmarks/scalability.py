@@ -1,9 +1,11 @@
 """Figure 12: cross-process scalability — launch the ``dist`` backend at
 1..N OS processes and report wall / wire-byte / skew curves.
 
-Each cell spawns ``nd`` single-device worker subprocesses through
-:func:`repro.launch.dist_worker.launch_local` (the container stand-in for
-one-command-per-host launches) and replays the *same* configuration
+This is the CPU gloo harness: each cell spawns ``nd`` single-device CPU
+worker subprocesses through :func:`repro.launch.dist_worker.launch_local`
+(a stand-in for one-command-per-host launches), and the run refuses to
+start on a host whose JAX backend is an accelerator, where one process
+drives all chips through ``mode="spmd"``.  It replays the *same* configuration
 in-process with ``mode="sim"`` as the parity reference.  Three gates run
 after the artifact is written:
 
@@ -35,7 +37,8 @@ from repro.core import Pattern, rads_enumerate
 from repro.core.driver import merge_process_stats
 from repro.graph import load_dataset, partition
 from repro.launch.dist_worker import (build_argparser, dist_available,
-                                      launch_local, worker_config)
+                                      launch_local, require_cpu_backend,
+                                      worker_config)
 from repro.configs.rads import QUERIES
 
 JSON_PATH = "BENCH_scalability.json"
@@ -99,6 +102,7 @@ def _gate_cell(workers: list[dict], sim_res) -> list[str]:
 
 def run(dataset="dblp_bench", queries=("q1", "q2"), ndevs=NDEVS,
         wire="raw", smoke=False, json_path=JSON_PATH):
+    require_cpu_backend()
     if smoke:
         queries = queries[:1]
     g = load_dataset(dataset)

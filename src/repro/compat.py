@@ -1,50 +1,25 @@
-"""Single source for version-drifting JAX APIs.
+"""Single home for the JAX APIs the engine reaches through one name.
 
-JAX moves fast and this repo has to run on whatever the container ships:
-
-* ``shard_map`` lived in ``jax.experimental.shard_map`` before being
-  promoted to ``jax.shard_map``;
-* ``jax.make_mesh`` predates its ``axis_types`` kwarg, and
-  ``jax.sharding.AxisType`` does not exist at all on 0.4.x;
-* ``Compiled.cost_analysis()`` returned a one-element *list* of dicts on
-  0.4.x and a plain dict later;
-* the ``jax.tree`` namespace (``jax.tree.map`` & co) replaced the older
-  ``jax.tree_util`` spellings;
-* compiled-executable persistence moved around: 0.4.x ships
-  ``jax.experimental.serialize_executable`` (pickles the underlying XLA
-  executable — true zero-recompile loads) while ``jax.export`` (also
-  present on 0.4.37) round-trips StableHLO that still needs an XLA compile
-  on load.  The AOT stage-executable cache
-  (:mod:`repro.runtime.compile_cache`) needs the former; both probes
-  degrade to ``None``/``False`` so the cache silently disables itself on
-  JAX builds without executable serialization;
-* multi-process CPU collectives: 0.4.x CPU backends only run cross-process
-  computations when the gloo TCP collectives implementation is selected
-  *before the backend client is created* (``jax.config.update(
-  "jax_cpu_collectives_implementation", "gloo")``) — newer builds default
-  to it, older ones lack it entirely.  The ``dist`` exchange backend
-  (:mod:`repro.core.exchange`) and its launcher go through
-  :func:`enable_cpu_collectives` / :func:`distributed_initialize` so the
-  whole bootstrap quirk surface stays in this file, and
-  ``host_local_array_to_global_array`` (the only blessed way to build a
-  process-global array from per-host values on 0.4.x) is wrapped by
-  :func:`global_shard` / :func:`global_replicate`.
-
-Every call-site in this repo imports the resolved symbol from here, so a
-JAX upgrade touches exactly this file.  Probes run once at import time and
-degrade gracefully (stub or fallback) rather than raising.
-
-Supported range: jax>=0.4.30,<0.6 (see pyproject.toml).
+Written for the installed JAX 0.9.0 (see pyproject.toml): ``jax.shard_map``,
+``jax.make_mesh`` with ``axis_types``, the ``jax.tree`` namespace,
+``jax.experimental.serialize_executable`` (pickles the compiled XLA
+executable, so a load needs no compile — what the AOT stage-executable
+store in :mod:`repro.runtime.compile_cache` relies on) and the gloo CPU
+collectives behind the multi-process ``dist`` backend
+(:func:`enable_cpu_collectives` / :func:`distributed_initialize`, with
+:func:`global_shard` / :func:`global_replicate` assembling process-global
+arrays from per-host values).  Call sites import these names from here.
 """
 from __future__ import annotations
 
-import inspect
-
 import jax
-from jax.sharding import Mesh, PartitionSpec
+from jax.experimental.serialize_executable import (
+    deserialize_and_load as _deserialize_and_load,
+    serialize as _serialize_executable)
+from jax.sharding import AxisType, Mesh, PartitionSpec
 
 __all__ = [
-    "AxisType", "HAS_AXIS_TYPES", "default_axis_types", "make_mesh",
+    "AxisType", "default_axis_types", "make_mesh",
     "shard_map", "tree_map", "tree_leaves", "tree_reduce",
     "tree_map_with_path", "with_sharding_constraint", "cost_analysis",
     "memory_analysis", "HAS_EXECUTABLE_SERIALIZATION", "serialize_compiled",
@@ -54,79 +29,31 @@ __all__ = [
 ]
 
 
-# --------------------------------------------------------------------------- #
-# shard_map: jax.shard_map (>=0.5) vs jax.experimental.shard_map (0.4.x)
-# --------------------------------------------------------------------------- #
-if hasattr(jax, "shard_map"):                                # pragma: no cover
-    _shard_map_impl = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
-    """Version-stable ``shard_map``: keyword-only, the common-subset
-    signature both implementations accept."""
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs)
+def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma=True):
+    """``jax.shard_map``, keyword-only."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 # --------------------------------------------------------------------------- #
-# Mesh construction: AxisType landed well after jax.make_mesh
+# Mesh construction
 # --------------------------------------------------------------------------- #
-try:
-    from jax.sharding import AxisType  # type: ignore[attr-defined]
-    HAS_AXIS_TYPES = True
-except ImportError:
-    class AxisType:  # minimal stand-in so callers can always name the enum
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-    HAS_AXIS_TYPES = False
-
-
 def default_axis_types(n_axes: int) -> tuple:
-    """``(AxisType.Auto,) * n_axes`` — or the stub equivalent pre-AxisType."""
+    """``(AxisType.Auto,) * n_axes``."""
     return (AxisType.Auto,) * n_axes
-
-
-_MAKE_MESH_KWARGS = (set(inspect.signature(jax.make_mesh).parameters)
-                     if hasattr(jax, "make_mesh") else set())
 
 
 def make_mesh(axis_shapes: tuple[int, ...], axis_names: tuple[str, ...],
               *, axis_types: tuple | None = None, devices=None) -> Mesh:
-    """``jax.make_mesh`` across versions.
-
-    ``axis_types`` is forwarded only where the installed JAX understands it
-    (it is a compiler hint, not a semantics change — dropping it is safe on
-    versions where every axis is implicitly Auto).  Pre-``jax.make_mesh``
-    versions fall back to ``mesh_utils.create_device_mesh`` + ``Mesh``.
-    """
-    if hasattr(jax, "make_mesh"):
-        kw = {}
-        if devices is not None:
-            kw["devices"] = devices
-        if axis_types is not None and HAS_AXIS_TYPES \
-                and "axis_types" in _MAKE_MESH_KWARGS:
-            kw["axis_types"] = axis_types
-        return jax.make_mesh(axis_shapes, axis_names, **kw)
-    from jax.experimental import mesh_utils                  # pragma: no cover
-    devs = mesh_utils.create_device_mesh(axis_shapes, devices=devices)
-    return Mesh(devs, axis_names)
+    """``jax.make_mesh`` (``devices=None`` takes the default devices)."""
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=axis_types,
+                         **kw)
 
 
-# --------------------------------------------------------------------------- #
-# Tree utilities: jax.tree namespace vs jax.tree_util
-# --------------------------------------------------------------------------- #
-if hasattr(jax, "tree") and hasattr(jax.tree, "map"):
-    tree_map = jax.tree.map
-    tree_leaves = jax.tree.leaves
-    tree_reduce = jax.tree.reduce
-else:                                                        # pragma: no cover
-    tree_map = jax.tree_util.tree_map
-    tree_leaves = jax.tree_util.tree_leaves
-    tree_reduce = jax.tree_util.tree_reduce
-
+tree_map = jax.tree.map
+tree_leaves = jax.tree.leaves
+tree_reduce = jax.tree.reduce
 tree_map_with_path = jax.tree_util.tree_map_with_path
 
 
@@ -135,8 +62,7 @@ tree_map_with_path = jax.tree_util.tree_map_with_path
 # --------------------------------------------------------------------------- #
 def with_sharding_constraint(x, *spec):
     """``lax.with_sharding_constraint`` or identity when no mesh is active
-    (single-device tests) — the historical behaviour also differs across
-    versions in *which* exception is raised, hence the broad except."""
+    (single-device tests)."""
     try:
         return jax.lax.with_sharding_constraint(x, PartitionSpec(*spec))
     except Exception:
@@ -147,18 +73,11 @@ def with_sharding_constraint(x, *spec):
 # Compiled-artifact introspection (dryrun / benchmarks)
 # --------------------------------------------------------------------------- #
 def cost_analysis(compiled) -> dict | None:
-    """Normalized ``Compiled.cost_analysis()``: always a dict (or None).
-
-    0.4.x returns ``[{...}]`` — one dict per partition — while newer JAX
-    returns the dict directly; callers doing ``key in cost`` silently read
-    nothing on the list form.
-    """
+    """``Compiled.cost_analysis()`` as a dict (or None)."""
     try:
         cost = compiled.cost_analysis()
     except Exception:
         return None
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
     return cost if isinstance(cost, dict) else None
 
 
@@ -173,14 +92,7 @@ def memory_analysis(compiled):
 # --------------------------------------------------------------------------- #
 # Compiled-executable persistence (the AOT stage-executable cache)
 # --------------------------------------------------------------------------- #
-try:
-    from jax.experimental.serialize_executable import (  # type: ignore
-        deserialize_and_load as _deserialize_and_load, serialize as
-        _serialize_executable)
-    HAS_EXECUTABLE_SERIALIZATION = True
-except ImportError:                                          # pragma: no cover
-    _serialize_executable = _deserialize_and_load = None
-    HAS_EXECUTABLE_SERIALIZATION = False
+HAS_EXECUTABLE_SERIALIZATION = True
 
 
 def serialize_compiled(compiled):
@@ -189,11 +101,8 @@ def serialize_compiled(compiled):
     The triple is exactly what :func:`deserialize_compiled` needs; the
     PyTreeDefs pickle as long as every custom node type (``WaveState``,
     ``DeviceGraph``, ``AdjCache``) is import-registered at load time, which
-    module import guarantees.  Raises on unsupported JAX builds — callers
-    should gate on :data:`HAS_EXECUTABLE_SERIALIZATION`.
+    module import guarantees.
     """
-    if _serialize_executable is None:                        # pragma: no cover
-        raise RuntimeError("this JAX build cannot serialize executables")
     return _serialize_executable(compiled)
 
 
@@ -201,8 +110,6 @@ def deserialize_compiled(triple):
     """Inverse of :func:`serialize_compiled`: returns a loaded executable
     callable with the original (pytree) calling convention — no tracing,
     no XLA compilation."""
-    if _deserialize_and_load is None:                        # pragma: no cover
-        raise RuntimeError("this JAX build cannot deserialize executables")
     payload, in_tree, out_tree = triple
     return _deserialize_and_load(payload, in_tree, out_tree)
 
@@ -220,19 +127,7 @@ def version_stamp() -> str:
 # --------------------------------------------------------------------------- #
 # Multi-process bootstrap (the `dist` exchange backend)
 # --------------------------------------------------------------------------- #
-def _probe_multiprocess_cpu() -> bool:
-    """Does this jaxlib ship the gloo TCP collectives the CPU backend needs
-    for cross-process computations?  (0.4.36 does; much older builds raise
-    "Multiprocess computations aren't implemented on the CPU backend".)"""
-    try:
-        from jax._src.lib import xla_client
-
-        return hasattr(xla_client._xla, "make_gloo_tcp_collectives")
-    except Exception:                                        # pragma: no cover
-        return False
-
-
-HAS_MULTIPROCESS_CPU = _probe_multiprocess_cpu()
+HAS_MULTIPROCESS_CPU = True
 
 
 def enable_cpu_collectives() -> bool:
@@ -240,34 +135,21 @@ def enable_cpu_collectives() -> bool:
 
     MUST run before the CPU backend client is created (i.e. before any
     computation or ``jax.devices()`` call) — the flag is read once at
-    client construction.  Returns False (no-op) on builds without gloo or
-    without the config knob; callers treat False as "multi-process
-    unavailable" and skip."""
-    if not HAS_MULTIPROCESS_CPU:
-        return False
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        return True
-    except Exception:                                        # pragma: no cover
-        return False
+    client construction.  Returns True."""
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
+    return True
 
 
 def distributed_initialize(coordinator_address: str, num_processes: int,
                            process_id: int) -> bool:
-    """``jax.distributed.initialize`` with the kwargs this build accepts.
+    """``jax.distributed.initialize`` with an explicit coordinator.
 
-    Returns False instead of raising when the build has no distributed
-    runtime or the bootstrap fails (coordinator unreachable) — the caller
-    degrades to single-process / skips."""
+    Returns False instead of raising when the bootstrap fails (coordinator
+    unreachable) — the caller degrades to single-process / skips."""
     try:
-        init = jax.distributed.initialize
-    except AttributeError:                                   # pragma: no cover
-        return False
-    kw = dict(coordinator_address=coordinator_address,
-              num_processes=num_processes, process_id=process_id)
-    accepted = set(inspect.signature(init).parameters)
-    try:
-        init(**{k: v for k, v in kw.items() if k in accepted})
+        jax.distributed.initialize(coordinator_address=coordinator_address,
+                                   num_processes=num_processes,
+                                   process_id=process_id)
         return True
     except Exception:
         return False
@@ -297,10 +179,9 @@ def global_shard(tree, mesh: Mesh, axis: str = "data"):
     ``mesh[axis]`` — the one entry point that works both single-process
     (plain ``device_put``) and multi-process (each process contributes its
     contiguous block of the leading axis through
-    ``multihost_utils.host_local_array_to_global_array``, the 0.4.x way to
-    assemble a global array; ``device_put`` onto non-addressable devices
-    raises there).  Every process must hold the FULL host value and call
-    with identical shapes — the per-process slice is taken here."""
+    ``multihost_utils.host_local_array_to_global_array``).  Every process
+    must hold the FULL host value and call with identical shapes — the
+    per-process slice is taken here."""
     import numpy as np
     from jax.sharding import NamedSharding
 

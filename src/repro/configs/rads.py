@@ -76,9 +76,12 @@ class EngineConfig:
                                        # background thread during group
                                        # formation (off the critical path)
     # --- accelerator kernels ------------------------------------------------ #
-    use_pallas_kernels: bool = False   # Pallas membership in back-edge checks +
-                                       # intersect in bucketed candidate gen
-                                       # (off on CPU: jnp reference is the test path)
+    use_pallas_kernels: bool = False   # off-TPU only: run the Pallas kernels
+                                       # (membership, intersect, varint) in
+                                       # interpret mode instead of the jnp
+                                       # refs; on TPU the compiled kernels
+                                       # always run (core/engine.py
+                                       # _kernel_mode)
 
     def __post_init__(self):
         # RL002's runtime twin: every escalation doubles the caps, and the
@@ -143,8 +146,11 @@ class EngineConfig:
 DATASETS: dict[str, dict] = {
     # sparse, huge diameter (RoadNet-like): 2-D lattice with perturbation
     "roadnet_synth": dict(kind="road", n=4096),
-    # small, moderately dense, community structure (DBLP-like)
-    "dblp_synth": dict(kind="powerlaw", n=2000, avg_deg=7, seed=1),
+    # DBLP-like at SNAP com-DBLP's published vertex count (317,080 vertices,
+    # 1,049,866 edges). Cut: preferential attachment with m=3 gives about
+    # 0.95 M edges (951,145 at seed 1) and far fewer triangles than DBLP's
+    # co-authorship clustering
+    "dblp_synth": dict(kind="powerlaw", n=317_080, avg_deg=7, seed=1),
     # dense social graph (LiveJournal-like)
     "livejournal_synth": dict(kind="powerlaw", n=6000, avg_deg=18, seed=2),
     # densest web graph (UK2002-like)

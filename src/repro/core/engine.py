@@ -57,8 +57,9 @@ over the stacked layout): the ``dense`` format is the seed's padded array,
 ``bucketed`` stores degree-bucketed CSR slabs — both produce byte-identical
 results because ``rows_at`` reassembles the same sentinel-padded windows.
 
-Accelerator kernels (gated by ``EngineConfig.use_pallas_kernels``, jnp refs
-as the CPU test path):
+Accelerator kernels (:func:`_kernel_mode`: on TPU they always run compiled;
+elsewhere the jnp refs run unless ``EngineConfig.use_pallas_kernels`` asks
+for the kernels in interpret mode, the CPU test of the kernel path):
 
 * membership tests (back-edge checks in ``_leaf_step`` on the dense layout
   and the ``verifyE`` answer path) route through
@@ -82,19 +83,27 @@ from repro.core.exchange import (ExchangeBackend, compact,
 from repro.core import wire as wire_codec
 from repro.core.plan import Plan
 from repro.graph.storage import DeviceGraph
-from repro.kernels.intersect.ops import (intersect as _intersect_op,
-                                         tile_defaults as _intersect_tiles)
+from repro.kernels.intersect.ops import intersect as _intersect_op
 from repro.kernels.membership.ops import membership as _membership_op
+
+
+def _kernel_mode(use_pallas: bool) -> tuple[bool, bool]:
+    """``(use_kernel, interpret)`` for the main-path Pallas kernels.
+
+    On TPU the compiled kernels always run, whatever the config says;
+    elsewhere ``use_pallas`` (``EngineConfig.use_pallas_kernels``) picks
+    the kernels in interpret mode over the jnp references."""
+    on_tpu = jax.default_backend() == "tpu"
+    return use_pallas or on_tpu, not on_tpu
 
 
 def _membership(rows: jnp.ndarray, vals: jnp.ndarray,
                 use_pallas: bool = False) -> jnp.ndarray:
-    """Back-edge / verifyE membership test, kernel-gated.
-
-    ``use_pallas=False`` is the jnp reference lowering (CPU test path);
-    ``True`` runs the Pallas kernel (interpreted off-TPU)."""
-    return _membership_op(rows, vals, use_kernel=use_pallas,
-                          interpret=jax.default_backend() != "tpu")
+    """Back-edge / verifyE membership test, kernel-gated (see
+    :func:`_kernel_mode`)."""
+    use_kernel, interpret = _kernel_mode(use_pallas)
+    return _membership_op(rows, vals, use_kernel=use_kernel,
+                          interpret=interpret)
 
 
 def _backedge_mask(g: DeviceGraph, w_row: jnp.ndarray, cand: jnp.ndarray,
@@ -109,15 +118,9 @@ def _backedge_mask(g: DeviceGraph, w_row: jnp.ndarray, cand: jnp.ndarray,
     invalidated — so the final masks are identical.
     """
     if g.intersect_backedge:
-        # tile the kernel against the *bucket* caps, not the padded window:
-        # on the bucketed layout every row's content fits the top cap, so
-        # small-bucket graphs get narrower m-chunks (less sentinel traffic)
-        caps = getattr(g, "bucket_caps", None)
-        bb, mc = _intersect_tiles(caps[-1]) if caps else (None, None)
+        use_kernel, interpret = _kernel_mode(cfg.use_pallas_kernels)
         mask, _ = _intersect_op(cand, w_row, sentinel=g.n,
-                                use_kernel=cfg.use_pallas_kernels,
-                                interpret=jax.default_backend() != "tpu",
-                                block_b=bb, m_chunk=mc)
+                                use_kernel=use_kernel, interpret=interpret)
         return mask
     return _membership(w_row, cand, cfg.use_pallas_kernels)
 
@@ -296,11 +299,12 @@ def fetch_exchange(g: DeviceGraph, exch: ExchangeBackend,
         # coded path: compacted varint id streams out, degree+delta coded
         # row streams back, sender scatters onto its hole positions
         req_cap, degs_cap, rows_cap = wire_codec.fetch_stream_caps(fcap, D)
-        interp = jax.default_backend() != "tpu"
+        use_kernel, interpret = _kernel_mode(use_pallas)
         req_s, req_len, req_raw, e_ov, model_ids = \
             wire_codec.encode_ids_lanes(wire, n, req_cap,
-                                        use_pallas=use_pallas,
-                                        interpret=interp)
+                                        use_pallas=use_kernel,
+                                        interpret=interpret,
+                                        per_device=exch.per_device)
         recv_s, recv_len, recv_raw = exch.a2a_tree((req_s, req_len, req_raw))
         dec_ids, dec_mask = wire_codec.decode_ids_lanes(
             recv_s, recv_len, recv_raw, fcap, n)
@@ -407,14 +411,19 @@ def verify_exchange(g: DeviceGraph, exch: ExchangeBackend,
 
     reqs_a, reqs_b, counts, slots, ov = jax.vmap(build)(ua, ub, umask, owners)
 
-    def answer(t, ra, rb):
+    def owned_rows(t, ra):
         li = jnp.clip(ra - t * stride, 0, stride - 1)
         local_ok = (ra // stride == t) & (ra < n)
-        rows = g.rows_at(t, li)                        # (src, vcap, D)
+        return g.rows_at(t, li), local_ok              # (src, vcap, D)
+
+    def member(rows, rb):                              # the kernel call
         D = rows.shape[-1]
-        memb = _membership(rows.reshape(-1, D), rb.reshape(-1, 1),
+        return _membership(rows.reshape(-1, D), rb.reshape(-1, 1),
                            use_pallas).reshape(rb.shape)
-        return memb & local_ok
+
+    def answer(ra, rb):
+        rows, local_ok = jax.vmap(owned_rows)(jnp.arange(ndev), ra)
+        return exch.per_device(member)(rows, rb) & local_ok
 
     if exch.wire_format == "varint":
         # coded path: EF(a) + run-delta varint(b) out, bit-packed bools back
@@ -425,7 +434,7 @@ def verify_exchange(g: DeviceGraph, exch: ExchangeBackend,
             (a_s, a_len, b_s, b_len, p_raw, counts))
         dec_a, dec_b, _ = wire_codec.decode_pairs_lanes(
             ra_s, ra_len, rb_s, rb_len, r_raw, r_counts, vcap, n, n)
-        ans = jax.vmap(answer)(jnp.arange(ndev), dec_a, dec_b)
+        ans = answer(dec_a, dec_b)
         ans_s, ans_len = wire_codec.pack_bools_lanes(ans, r_counts, ans_cap)
         back_s, _ = exch.a2a_tree((ans_s, ans_len))
         back = wire_codec.unpack_bools_lanes(back_s, counts, vcap)
@@ -438,7 +447,7 @@ def verify_exchange(g: DeviceGraph, exch: ExchangeBackend,
         # the (a, b) request buffers travel as one sub-state through the
         # backend
         recv_a, recv_b = exch.a2a_tree((reqs_a, reqs_b))
-        ans = jax.vmap(answer)(jnp.arange(ndev), recv_a, recv_b)
+        ans = answer(recv_a, recv_b)
         back = exch.a2a(ans)                           # (ndev, peer, vcap)
         wire_bytes = None
 
@@ -463,18 +472,19 @@ def verify_exchange(g: DeviceGraph, exch: ExchangeBackend,
 # --------------------------------------------------------------------------- #
 # Leaf expansion
 # --------------------------------------------------------------------------- #
-def _leaf_step(g: DeviceGraph, cfg: EngineConfig, spec: StepSpec,
-               k_off: int, rows, alive, seed_slot,
+def _leaf_step(g: DeviceGraph, cfg: EngineConfig, exch: ExchangeBackend,
+               spec: StepSpec, k_off: int, rows, alive, seed_slot,
                pend_a, pend_b, pend_m, req_ids, fetched, local_only: bool):
     """Expand one leaf: candidates = adj(pivot); filter (injectivity,
     symmetry, degree, local back-edge intersection — Alg. 1+2); compact to
     frontier_cap; record undetermined edges into the pending (EVI) buffers.
-    Adjacency is read through the format-agnostic ``DeviceGraph``."""
+    Adjacency is read through the format-agnostic ``DeviceGraph``; the
+    back-edge kernel runs through ``exch.per_device``."""
     ndev, stride, n, D = g.ndev, g.stride, g.n, g.max_degree
     cap = cfg.frontier_cap
     t_ids = jnp.arange(ndev)
 
-    def dev(t, rws, alv, sslot, pa, pb, pm, rq, ft):
+    def candidates(t, rws, alv, rq, ft):
         R, w = rws.shape
         pv = rws[:, spec.piv_col]
         is_local = (pv // stride == t) & (pv < n)
@@ -507,13 +517,30 @@ def _leaf_step(g: DeviceGraph, cfg: EngineConfig, spec: StepSpec,
         valid &= jnp.where(c_local, g.deg_at(t, c_li) >= spec.leaf_deg, True)
         if local_only:
             valid &= c_local                               # Prop. 1 pruning
-        for c in spec.back_cols:       # local checks (Alg 2 lines 3-5, 8-11)
+        # back-edge windows for the local checks (Alg 2 lines 3-5, 8-11)
+        w_rows, w_locs = [], []
+        for c in spec.back_cols:
             wv = rws[:, c]
-            w_loc = (wv // stride == t) & (wv < n)
-            w_row = g.rows_at(t, jnp.clip(wv - t * stride, 0, stride - 1))
-            valid &= jnp.where(
-                w_loc[:, None], _backedge_mask(g, w_row, cand, cfg), True)
+            w_locs.append((wv // stride == t) & (wv < n))
+            w_rows.append(g.rows_at(t, jnp.clip(wv - t * stride, 0,
+                                                stride - 1)))
+        return cand, valid, lost, tuple(w_rows), tuple(w_locs)
 
+    if local_only:
+        cand, valid, losts, w_rows, w_locs = jax.vmap(
+            lambda t, rws, alv: candidates(t, rws, alv, None, None))(
+            t_ids, rows, alive)
+    else:
+        cand, valid, losts, w_rows, w_locs = jax.vmap(candidates)(
+            t_ids, rows, alive, req_ids, fetched)
+    backedge = exch.per_device(
+        lambda w_row, cd: _backedge_mask(g, w_row, cd, cfg))
+    for k in range(len(spec.back_cols)):
+        valid &= jnp.where(w_locs[k][..., None], backedge(w_rows[k], cand),
+                           True)
+
+    def compact_dev(t, rws, sslot, pa, pb, pm, cand, valid):
+        R = rws.shape[0]
         # compact (R*D) -> cap
         parent = jnp.repeat(jnp.arange(R, dtype=jnp.int32), D)
         new_mask, ov, parent_c, cand_c = compact(
@@ -539,17 +566,11 @@ def _leaf_step(g: DeviceGraph, cfg: EngineConfig, spec: StepSpec,
             pa_n = pa_n.at[:, k_off + k].set(jnp.where(need, a_val, n))
             pb_n = pb_n.at[:, k_off + k].set(jnp.where(need, b_val, n))
             pm_n = pm_n.at[:, k_off + k].set(need)
-        return new_rows, new_mask, new_slot, pa_n, pb_n, pm_n, ov, lost
+        return new_rows, new_mask, new_slot, pa_n, pb_n, pm_n, ov
 
-    if local_only:
-        def dev_local(t, rws, alv, sslot, pa, pb, pm):
-            return dev(t, rws, alv, sslot, pa, pb, pm, None, None)
-        outs = jax.vmap(dev_local)(t_ids, rows, alive, seed_slot,
-                                   pend_a, pend_b, pend_m)
-    else:
-        outs = jax.vmap(dev)(t_ids, rows, alive, seed_slot,
-                             pend_a, pend_b, pend_m, req_ids, fetched)
-    rows, alive, seed_slot, pend_a, pend_b, pend_m, ovs, losts = outs
+    rows, alive, seed_slot, pend_a, pend_b, pend_m, ovs = jax.vmap(
+        compact_dev)(t_ids, rows, seed_slot, pend_a, pend_b, pend_m,
+                     cand, valid)
     return (rows, alive, seed_slot, pend_a, pend_b, pend_m,
             jnp.any(ovs), jnp.any(losts))
 
@@ -680,8 +701,8 @@ def fetch_stage(g: DeviceGraph, pd: PlanData, cfg: EngineConfig,
 
 
 def expand_stage(g: DeviceGraph, pd: PlanData, cfg: EngineConfig,
-                 ui: int, state: WaveState, bufs, local_only: bool
-                 ) -> WaveState:
+                 exch: ExchangeBackend, ui: int, state: WaveState, bufs,
+                 local_only: bool) -> WaveState:
     """Pipeline stage 2 of unit ``ui``: every leaf step of the unit —
     candidate generation from (local ∪ fetched) adjacency, injectivity /
     symmetry / degree / local-membership filters, frontier compaction, and
@@ -699,7 +720,7 @@ def expand_stage(g: DeviceGraph, pd: PlanData, cfg: EngineConfig,
     for sid in step_ids:
         spec = pd.steps[sid]
         (rows, alive, seed_slot, pend_a, pend_b, pend_m, ov_s, lost_s
-         ) = _leaf_step(g, cfg, spec, k_off,
+         ) = _leaf_step(g, cfg, exch, spec, k_off,
                         rows, alive, seed_slot, pend_a, pend_b, pend_m,
                         req_ids, fetched, local_only)
         overflow |= ov_s
@@ -792,6 +813,6 @@ def run_rounds(g: DeviceGraph, pd: PlanData, cfg: EngineConfig,
     for ui in range(len(pd.unit_steps)):
         state, bufs, cache = fetch_stage(g, pd, cfg, exch, ui, state,
                                          local_only, cache)
-        state = expand_stage(g, pd, cfg, ui, state, bufs, local_only)
+        state = expand_stage(g, pd, cfg, exch, ui, state, bufs, local_only)
         state = verify_stage(g, pd, cfg, exch, ui, state, local_only)
     return finalize_wave(state)

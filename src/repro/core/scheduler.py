@@ -63,7 +63,6 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-import warnings
 from collections import deque
 from dataclasses import dataclass, field
 
@@ -230,6 +229,7 @@ class StageRunner:
         self._hits_pending = 0.0  # store hits awaiting wave attribution
         self._plan_repr = repr(pd)
         self._prewarm_threads: list[threading.Thread] = []
+        self._prewarm_errors: list[Exception] = []
         self._tl = threading.local()   # per-thread last-resolve source
                                        # ("slot" | "store" | "compile")
 
@@ -280,6 +280,14 @@ class StageRunner:
         escalation) so the run total stays exact."""
         with self._lock:
             self._hits_pending += float(h)
+
+    def executables(self) -> dict:
+        """The resolved stage executables, ``(key, caps, sig) -> Compiled``
+        (or a loaded store entry) — what a caller inspects to see which
+        kernels the compiler put in (``as_text()``)."""
+        with self._lock:
+            return {k: v for k, v in self._slots.items()
+                    if not isinstance(v, threading.Event)}
 
     # -- stage resolution ---------------------------------------------------- #
     @staticmethod
@@ -376,9 +384,9 @@ class StageRunner:
                                                     s, False, c))
 
     def _make_expand(self, ui: int, local_only: bool, cfg: EngineConfig):
-        pd = self.pd
-        return jax.jit(lambda gg, s, b: expand_stage(gg, pd, cfg, ui, s, b,
-                                                     local_only))
+        pd, exch = self.pd, self.exch
+        return jax.jit(lambda gg, s, b: expand_stage(gg, pd, cfg, exch, ui,
+                                                     s, b, local_only))
 
     def _make_verify(self, ui: int, local_only: bool, cfg: EngineConfig):
         pd, exch = self.pd, self.exch
@@ -469,7 +477,7 @@ class StageRunner:
                           lambda: self._make_expand(ui, local_only, cfg),
                           args, cfg)
             state = jax.eval_shape(
-                lambda gg, s, b: expand_stage(gg, pd, cfg, ui, s, b,
+                lambda gg, s, b: expand_stage(gg, pd, cfg, exch, ui, s, b,
                                               local_only), *args)
             args = (g, state)
             self._resolve(("verify", ui, local_only),
@@ -525,14 +533,14 @@ class StageRunner:
         """Run :meth:`prewarm` on a daemon thread (the driver launches this
         right before each scheduler phase, so compilation overlaps group
         formation).  Join via :meth:`join_prewarm` before reading
-        ``compiles``/``compile_s``.  Pre-warm is advisory: a failure warns
-        and the main path compiles on demand as before."""
+        ``compiles``/``compile_s``; it re-raises the first failure the
+        thread recorded, so a stage the compiler refuses fails the run at
+        its cause."""
         def work():
             try:
                 self.prewarm(scap, local_only, escalation_rungs)
-            except Exception as e:
-                warnings.warn(f"stage pre-warm (scap={scap}, local_only="
-                              f"{local_only}) failed: {e!r}", RuntimeWarning)
+            except Exception as e:   # handed to the joining thread
+                self._prewarm_errors.append(e)
         th = threading.Thread(target=work, name="rads-stage-prewarm",
                               daemon=True)
         th.start()
@@ -540,9 +548,14 @@ class StageRunner:
         return th
 
     def join_prewarm(self) -> None:
+        """Wait for every pre-warm thread; re-raise the first failure."""
         for th in self._prewarm_threads:
             th.join()
         self._prewarm_threads.clear()
+        if self._prewarm_errors:
+            err = self._prewarm_errors[0]
+            self._prewarm_errors.clear()
+            raise err
 
 
 # --------------------------------------------------------------------------- #

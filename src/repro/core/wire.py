@@ -457,17 +457,22 @@ def unpack_bools(stream: jnp.ndarray, count, m_out: int) -> jnp.ndarray:
 # Lane-grid wrappers (ndev, peer, ...) — what the engine stages call
 # --------------------------------------------------------------------------- #
 def encode_ids_lanes(wire: jnp.ndarray, sentinel: int, cap: int,
-                     use_pallas: bool = False, interpret: bool = True):
+                     use_pallas: bool = False, interpret: bool = True,
+                     per_device=jax.vmap):
     """``wire`` (ndev, peer, m): per-lane :func:`encode_ids`, with the
-    delta/varint-size pass batched over all lanes (Pallas fast path).
+    delta/varint-size pass batched over each device's lanes (Pallas fast
+    path; ``per_device`` maps it over the devices — the exchange
+    backend's :meth:`~repro.core.exchange.ExchangeBackend.per_device`).
 
-    Also returns the per-lane PR 4 *modeled* byte matrix (varints capped
+    Also returns the per-lane *modeled* byte matrix (varints capped
     at 4 B — ``engine._varint_id_bytes`` semantics) reusing the same
     sizing pass, so the jitted fetch stage never sizes the lanes twice."""
     ndev, p, m = wire.shape
     flat = wire.reshape(-1, m)
-    delta, vlen = delta_vlen(flat, sentinel, use_kernel=use_pallas,
-                             interpret=interpret)
+    delta, vlen = per_device(
+        lambda w: delta_vlen(w, sentinel, use_kernel=use_pallas,
+                             interpret=interpret))(wire)
+    delta, vlen = delta.reshape(-1, m), vlen.reshape(-1, m)
     s, ln, rw, ov = jax.vmap(
         lambda i, d, v: _encode_ids_core(i, d, v, cap))(flat, delta, vlen)
     model = jnp.minimum(vlen, 4).sum(-1).reshape(ndev, p)

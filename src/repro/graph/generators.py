@@ -34,19 +34,26 @@ def powerlaw_graph(n: int, avg_deg: int, seed: int = 0) -> Graph:
     """Barabasi-Albert-style preferential attachment (social/web stand-in)."""
     m = max(avg_deg // 2, 1)
     rng = np.random.default_rng(seed)
-    edges = []
-    targets = list(range(m))          # initial clique-ish core
-    repeated: list[int] = list(range(m))
+    steps = max(n - m, 0)
+    # repeated-endpoint pool, preallocated: the first k entries are live, so
+    # each draw is O(m) instead of rebuilding an O(|pool|) array per vertex
+    pool = np.empty(m + 2 * m * steps, dtype=np.int64)
+    pool[:m] = np.arange(m)
+    k = m
+    src = np.empty(m * steps, dtype=np.int64)
+    dst = np.empty(m * steps, dtype=np.int64)
+    e = 0
     for v in range(m, n):
         # preferential: sample from the repeated-endpoint pool
-        pool = np.array(repeated, dtype=np.int64)
-        tg = rng.choice(pool, size=m, replace=True)
-        tg = np.unique(tg)
-        for t in tg:
-            edges.append((v, int(t)))
-            repeated.append(int(t))
-            repeated.append(v)
-    return Graph.from_edges(n, np.array(edges, dtype=np.int64))
+        tg = np.unique(rng.choice(pool[:k], size=m, replace=True))
+        c = tg.shape[0]
+        src[e:e + c] = v
+        dst[e:e + c] = tg
+        e += c
+        pool[k:k + 2 * c:2] = tg
+        pool[k + 1:k + 2 * c:2] = v
+        k += 2 * c
+    return Graph.from_edges(n, np.stack([src[:e], dst[:e]], axis=1))
 
 
 def erdos_graph(n: int, avg_deg: float, seed: int = 0) -> Graph:

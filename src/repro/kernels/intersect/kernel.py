@@ -3,58 +3,21 @@ candidate refinement C(u) <- adj(piv) ∩ adj(f(u'))).
 
 ``a (B, M)`` and ``b (B, M)`` are sorted, sentinel-padded adjacency windows.
 Output: ``mask (B, M) bool`` marking a-entries present in b, and
-``count (B,) int32``. Same VPU chunk-compare scheme as the membership
-kernel (no dynamic gather), tiled over B via BlockSpec; the count is an
-in-kernel reduction so callers can size compaction without a second pass.
+``count (B,) int32``.  "a[r, i] in b[r]" is exactly the membership test, so
+this runs the membership kernel with ``b`` as the rows and ``a`` as the
+queries; a's sentinel entries are mapped below every row value first, so
+all-padding tiles fall to the kernel's range skip.
 """
 from __future__ import annotations
 
-from functools import partial
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-
-def _intersect_kernel(a_ref, b_ref, mask_ref, cnt_ref, *, m_chunk: int,
-                      sentinel: int):
-    a = a_ref[...]
-    b = b_ref[...]
-    TB, M = a.shape
-    acc = jnp.zeros((TB, M), dtype=jnp.bool_)
-    n_chunks = M // m_chunk
-
-    def body(c, acc):
-        chunk = jax.lax.dynamic_slice(b, (0, c * m_chunk), (TB, m_chunk))
-        hit = (a[:, :, None] == chunk[:, None, :]).any(axis=-1)
-        return acc | hit
-
-    acc = jax.lax.fori_loop(0, n_chunks, body, acc)
-    acc = acc & (a != sentinel)
-    mask_ref[...] = acc
-    cnt_ref[...] = acc.sum(axis=-1, dtype=jnp.int32)
+from repro.kernels.membership.kernel import NO_MATCH, membership_pallas
 
 
 def intersect_pallas(a: jnp.ndarray, b: jnp.ndarray, sentinel: int,
-                     block_b: int = 256, m_chunk: int = 128,
-                     interpret: bool = True):
-    B, M = a.shape
-    m_chunk = min(m_chunk, max(M, 1))
-    Mp = -(-M // m_chunk) * m_chunk
-    Bp = -(-B // block_b) * block_b
-    pad_a = jnp.pad(a, ((0, Bp - B), (0, Mp - M)), constant_values=sentinel)
-    pad_b = jnp.pad(b, ((0, Bp - B), (0, Mp - M)),
-                    constant_values=jnp.iinfo(jnp.int32).min)
-    grid = (Bp // block_b,)
-    mask, cnt = pl.pallas_call(
-        partial(_intersect_kernel, m_chunk=m_chunk, sentinel=sentinel),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_b, Mp), lambda i: (i, 0)),
-                  pl.BlockSpec((block_b, Mp), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((block_b, Mp), lambda i: (i, 0)),
-                   pl.BlockSpec((block_b,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((Bp, Mp), jnp.bool_),
-                   jax.ShapeDtypeStruct((Bp,), jnp.int32)],
-        interpret=interpret,
-    )(pad_a, pad_b)
-    return mask[:B, :M], cnt[:B]
+                     block_b: int | None = None, interpret: bool = True):
+    live = a != sentinel
+    mask = membership_pallas(b, jnp.where(live, a, NO_MATCH),
+                             block_b=block_b, interpret=interpret) & live
+    return mask, mask.sum(axis=-1, dtype=jnp.int32)

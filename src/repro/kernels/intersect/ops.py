@@ -5,43 +5,35 @@ import jax.numpy as jnp
 
 from repro.kernels.intersect.kernel import intersect_pallas
 from repro.kernels.intersect.ref import intersect_ref
-
-
-def _pow2ceil(x: int) -> int:
-    return 1 << max(int(x) - 1, 0).bit_length()
+from repro.kernels.membership.kernel import LANES, block_rows
 
 
 def tile_defaults(m: int) -> tuple[int, int]:
-    """Tuned ``(block_b, m_chunk)`` for window width ``m``.
+    """``(block_b, lane_chunk)`` for window width ``m``.
 
-    Degree-bucketed adjacency keeps most windows far narrower than
-    ``max_degree``, so the chunk is the window width rounded up to a
-    power of two (capped at the 128-lane VPU width) — small buckets get
-    narrow tiles instead of streaming full-width chunks of sentinel
-    padding; with a narrow chunk the batch tile is widened so the
-    (block_b, m_chunk) working set keeps feeding the VPU.
+    The chunk is always the 128-lane VPU width (narrow windows are padded
+    to it, never narrowed: Mosaic slices lanes in multiples of 128); the
+    batch tile shrinks as the window widens so the resident
+    ``(block_b, ceil128(m))`` row block keeps fitting VMEM.
     """
-    m_chunk = min(128, _pow2ceil(max(m, 1)))
-    block_b = 256 if m_chunk >= 64 else 512
-    return block_b, m_chunk
+    return block_rows(m), LANES
 
 
 @partial(jax.jit, static_argnames=("sentinel", "use_kernel", "interpret",
-                                   "block_b", "m_chunk"))
+                                   "block_b"))
 def intersect(a: jnp.ndarray, b: jnp.ndarray, sentinel: int,
               use_kernel: bool = False, interpret: bool = True,
-              block_b: int | None = None, m_chunk: int | None = None):
+              block_b: int | None = None):
     """Sorted-list intersection: (mask over a, per-row count).
 
-    ``block_b``/``m_chunk`` tune the Pallas tiling; ``None`` picks
-    :func:`tile_defaults` from the ``b`` window width (narrow degree
-    buckets get narrow chunks).  The jnp reference ignores the tiling, so
-    any (block_b, m_chunk) is bit-identical to ``use_kernel=False``.
+    ``block_b`` tunes the Pallas batch tile; ``None`` picks
+    :func:`tile_defaults` from the ``b`` window width.  The jnp reference
+    ignores the tiling, so any ``block_b`` is bit-identical to
+    ``use_kernel=False``.
     """
     if use_kernel:
-        db, dm = tile_defaults(b.shape[-1])
         return intersect_pallas(a, b, sentinel,
-                                block_b=block_b or db,
-                                m_chunk=m_chunk or dm,
+                                block_b=block_b or tile_defaults(
+                                    b.shape[-1])[0],
                                 interpret=interpret)
     return intersect_ref(a, b, sentinel)

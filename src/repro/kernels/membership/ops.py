@@ -13,8 +13,9 @@ def membership(rows: jnp.ndarray, vals: jnp.ndarray,
                use_kernel: bool = False, interpret: bool = True) -> jnp.ndarray:
     """Edge-existence / candidate-refinement membership test.
 
-    ``use_kernel=True`` runs the Pallas kernel (interpret=True on CPU);
-    the default jnp path is what the engine uses on this CPU container."""
+    ``use_kernel=True`` runs the Pallas kernel (``interpret=True`` off
+    TPU), ``False`` the jnp reference; the engine decides both from the
+    platform (``repro.core.engine._kernel_mode``)."""
     if use_kernel:
         return membership_pallas(rows, vals, interpret=interpret)
     return membership_ref(rows, vals)

@@ -23,8 +23,11 @@ them with :func:`repro.core.driver.merge_process_stats`, which *asserts*
 that identity.
 
 :func:`launch_local` spawns N single-device worker subprocesses against a
-coordinator on a free localhost port — the container stand-in for real
-multi-host launches (same flags, one host).
+coordinator on a free localhost port — the CPU gloo harness of the ``dist``
+backend, a stand-in for real multi-host launches (same flags, one host).
+It forces ``JAX_PLATFORMS=cpu`` on its workers, so it refuses to run on a
+host whose JAX backend is an accelerator (:func:`require_cpu_backend`):
+there one process drives every chip through ``mode="spmd"``.
 """
 from __future__ import annotations
 
@@ -209,9 +212,26 @@ def _src_dir() -> str:
     return os.path.dirname(os.path.abspath(next(iter(repro.__path__))))
 
 
+def require_cpu_backend() -> None:
+    """Raise unless this process's JAX backend is the CPU: the local
+    multi-process harness measures CPU worker processes, which on an
+    accelerator host would stand in silently for the chips."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"launch_local is the CPU gloo harness of the dist backend, but "
+            f"this host's JAX backend is {backend!r}; on an accelerator host "
+            f"one process drives every chip: run mode='spmd' on "
+            f"make_engine_mesh(n)")
+
+
 def launch_local(nproc: int, worker_args: list[str],
                  timeout_s: float = 1200.0) -> list[dict] | None:
-    """Run one ``dist`` enumeration across ``nproc`` local subprocesses.
+    """Run one ``dist`` enumeration across ``nproc`` local CPU subprocesses
+    (the gloo harness; raises on an accelerator host, see
+    :func:`require_cpu_backend`).
 
     Each worker gets exactly one CPU device
     (``--xla_force_host_platform_device_count=1``) so the process axis IS
@@ -220,6 +240,7 @@ def launch_local(nproc: int, worker_args: list[str],
     ordered by process id, or ``None`` when the bootstrap is unavailable
     (any worker exited ``EXIT_BOOTSTRAP_UNAVAILABLE``); any other failure
     raises with the worker's output attached."""
+    require_cpu_backend()
     port = _free_port()
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"

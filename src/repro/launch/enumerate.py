@@ -4,11 +4,13 @@
 from __future__ import annotations
 
 import argparse
+import os
 import time
 
 from repro.configs.rads import CLIQUE_QUERIES, DEFAULT_ENGINE, QUERIES, EngineConfig
 from repro.core import Pattern, best_plan, rads_enumerate
 from repro.graph import load_dataset, partition
+from repro.runtime.compile_cache import configure_jax_cache
 
 
 def main():
@@ -29,8 +31,9 @@ def main():
     ap.add_argument("--no-steal-groups", action="store_true",
                     help="disable steal-from-longest group-queue refill")
     ap.add_argument("--pallas", action="store_true",
-                    help="Pallas kernels: membership in back-edge checks, "
-                         "intersect in bucketed candidate generation")
+                    help="off TPU: run the Pallas kernels in interpret "
+                         "mode instead of the jnp references (on TPU the "
+                         "compiled kernels always run)")
     ap.add_argument("--wire", default="raw",
                     choices=["raw", "varint", "auto"],
                     help="exchange wire format: raw int32 slabs, "
@@ -38,11 +41,12 @@ def main():
                          "measured per-run auto-selection from persisted "
                          "wire trials (needs --priors; core/wire.py; "
                          "results are identical)")
-    ap.add_argument("--compile-cache", default="",
-                    help="per-host directory for the persistent stage-"
-                         "executable store (runtime/compile_cache.py); "
-                         "warm runs deserialize executables instead of "
-                         "tracing ('' = disabled)")
+    ap.add_argument("--compile-cache", action="store_true",
+                    help="enable the persistent stage-executable store "
+                         "(runtime/compile_cache.py) in the stagex "
+                         "subdirectory of JAX's compilation cache "
+                         "directory; warm runs deserialize executables "
+                         "instead of tracing")
     ap.add_argument("--no-prewarm", action="store_true",
                     help="disable background stage pre-warm (resolve the "
                          "jit ladder off the critical path)")
@@ -71,6 +75,7 @@ def main():
                          "*.prom = Prometheus textfile format, anything "
                          "else = JSON document with kind/unit/desc")
     args = ap.parse_args()
+    cache_dir = configure_jax_cache()
     depth = args.pipeline_depth if args.pipeline_depth == "auto" \
         else int(args.pipeline_depth)
 
@@ -102,7 +107,9 @@ def main():
                                            else DEFAULT_ENGINE.cache_decay),
                               wire_format=args.wire,
                               priors_path=args.priors,
-                              compile_cache_dir=args.compile_cache,
+                              compile_cache_dir=(
+                                  os.path.join(cache_dir, "stagex")
+                                  if args.compile_cache else ""),
                               prewarm=not args.no_prewarm)
     mesh = None
     if args.mode == "spmd":

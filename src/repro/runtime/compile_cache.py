@@ -96,7 +96,29 @@ from repro import compat
 from repro.obs.metrics import COUNTER, Instrument, MetricsRegistry
 
 __all__ = ["StageExecCache", "arg_signature", "code_fingerprint",
-           "stage_context", "build_exec_cache"]
+           "stage_context", "build_exec_cache", "configure_jax_cache"]
+
+# JAX's persistent compilation cache when JAX_COMPILATION_CACHE_DIR is not
+# set: one fixed, git-ignored directory in the checkout (the path is part
+# of what makes a later run find its entries, so it never moves)
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def configure_jax_cache() -> str:
+    """Place JAX's persistent compilation cache and return its directory.
+
+    Entry points call this before their first compile.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps its cache
+    there and nothing is changed; otherwise the cache goes to
+    :data:`CHECKOUT_CACHE_DIR`.  The stage-executable store, where it is
+    enabled, lives in the ``stagex`` subdirectory of the returned path."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", CHECKOUT_CACHE_DIR)
+    return CHECKOUT_CACHE_DIR
 
 
 def _store_stats_registry() -> MetricsRegistry:

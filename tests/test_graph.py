@@ -79,3 +79,31 @@ def test_icosahedral_multimesh_counts():
         assert v.shape[0] == 10 * 4 ** r + 2
         assert e.shape[0] == 30 * sum(4 ** i for i in range(r + 1))
         np.testing.assert_allclose(np.linalg.norm(v, axis=1), 1.0, rtol=1e-5)
+
+
+# sha256 of (indptr, indices) as generated before powerlaw_graph's pool
+# became preallocated: the O(n·m) generator must draw the same graphs
+POWERLAW_PINS = {
+    "dblp_bench": (
+        "467c18098fcb3c29ca3189d84b4b1eecc010cb844aeb2053692b5323c207ac3b",
+        "09d4ff3d67093f56ab7f2be3836e0158491b6e4e540e212a43f290a6dbab7123"),
+    "livejournal_bench": (
+        "133ffaa69deaa714e80450ff22255ad3ffe700f1e209ba01094a5ad88289e792",
+        "0f9dbd009508d4387f62a178a1e122e34d4668a3c72238aa7ae508162495edd4"),
+    "uk2002_bench": (
+        "c0de9d2a4f47862c1c84d2fa35194971bf0a7074cc458c3872a8833be8adaadd",
+        "c5734096d39ee847913b1fa79191773b0493f111fb0fe235dc80b576b60fb023"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(POWERLAW_PINS))
+def test_powerlaw_graph_pinned(name):
+    import hashlib
+
+    from repro.graph import load_dataset
+
+    g = load_dataset(name)
+    assert g.indptr.dtype == np.int64 and g.indices.dtype == np.int32
+    got = (hashlib.sha256(g.indptr.tobytes()).hexdigest(),
+           hashlib.sha256(g.indices.tobytes()).hexdigest())
+    assert got == POWERLAW_PINS[name]

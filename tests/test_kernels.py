@@ -39,22 +39,19 @@ def test_intersect_sweep(B, M):
     assert np.array_equal(np.asarray(c1), np.asarray(c2))
 
 
-@pytest.mark.parametrize("block_b,m_chunk", [(None, None), (64, 8),
-                                             (128, 32), (512, 128),
-                                             (256, 16)])
+@pytest.mark.parametrize("block_b", [None, 8, 16, 64, 512])
 @pytest.mark.parametrize("B,M", [(17, 8), (40, 65), (9, 200)])
-def test_intersect_tile_parity(B, M, block_b, m_chunk):
-    """The exposed block_b/m_chunk tiling kwargs (and the bucket-cap-tuned
-    defaults, block_b=None/m_chunk=None) never change the result — any
-    tile shape is bit-identical to the jnp reference."""
+def test_intersect_tile_parity(B, M, block_b):
+    """The exposed block_b tiling kwarg (and the window-width default,
+    block_b=None) never changes the result — any batch tile is
+    bit-identical to the jnp reference."""
     from repro.kernels.intersect.ops import intersect
     rng = np.random.default_rng(B * M + (block_b or 0))
     sent = 300
     a = np.sort(rng.integers(0, sent, (B, M)).astype(np.int32), axis=1)
     b = np.sort(rng.integers(0, sent, (B, M)).astype(np.int32), axis=1)
     m1, c1 = intersect(jnp.asarray(a), jnp.asarray(b), sent,
-                       use_kernel=True, interpret=True,
-                       block_b=block_b, m_chunk=m_chunk)
+                       use_kernel=True, interpret=True, block_b=block_b)
     m2, c2 = intersect(jnp.asarray(a), jnp.asarray(b), sent,
                        use_kernel=False)
     assert np.array_equal(np.asarray(m1), np.asarray(m2))
@@ -62,14 +59,15 @@ def test_intersect_tile_parity(B, M, block_b, m_chunk):
 
 
 def test_intersect_tile_defaults():
-    """The tuned defaults narrow the chunk for small bucket caps (and
-    widen the batch tile to compensate); wide windows keep the 128-lane
-    chunk."""
+    """Lane chunks are always 128 wide (narrow windows are padded, never
+    narrowed); the batch tile shrinks as the window widens so the resident
+    row block keeps fitting VMEM."""
     from repro.kernels.intersect.ops import tile_defaults
-    assert tile_defaults(8) == (512, 8)
-    assert tile_defaults(1) == (512, 1)
-    assert tile_defaults(200) == (256, 128)
-    assert tile_defaults(64) == (256, 64)
+    assert tile_defaults(8) == (512, 128)
+    assert tile_defaults(1) == (512, 128)
+    assert tile_defaults(200) == (512, 128)
+    assert tile_defaults(1796) == (256, 128)
+    assert tile_defaults(1 << 14) == (32, 128)
 
 
 @pytest.mark.parametrize("B,M", [(3, 16), (7, 130), (260, 64), (1, 300)])

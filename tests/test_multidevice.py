@@ -153,6 +153,19 @@ def test_spmd_wire_varint_matches_sim():
     assert res["ok"]
 
 
+def test_launch_local_refuses_accelerator_backend(monkeypatch):
+    """``launch_local`` is the CPU gloo harness: where JAX's backend is an
+    accelerator it raises before starting any worker, instead of measuring
+    CPU processes in place of the chips."""
+    import jax
+
+    from repro.launch.dist_worker import launch_local
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="CPU gloo harness"):
+        launch_local(2, ["--dataset", "dblp_bench"])
+
+
 @pytest.mark.slow
 @pytest.mark.parametrize("wire,cache", [("raw", False), ("raw", True),
                                         ("varint", False),

@@ -206,3 +206,23 @@ def test_prewarm_escalation_rung_precompiles_next_caps():
     res = rads_enumerate(pg, pat, cfg, mode="sim")
     assert res.count == len(oracle)
     assert canonicalize(res.embeddings, pat) == oracle
+
+
+def test_join_prewarm_reraises_prewarm_failure(monkeypatch):
+    """A stage the compiler refuses during background pre-warm fails the
+    run at its cause: ``join_prewarm`` re-raises the first failure the
+    pre-warm thread recorded instead of turning it into a warning."""
+    g = erdos_graph(40, 3.0, seed=1)
+    pg = partition(g, 2, method="bfs")
+    pd = build_plan_data(best_plan(Pattern.from_edges(QUERIES["q1"])))
+    runner = StageRunner(device_graph(pg, "dense"), pd, CFG,
+                         Exchange("sim"))
+
+    def refused(*a, **k):
+        raise NotImplementedError("kernel refused by the compiler")
+
+    monkeypatch.setattr(runner, "prewarm", refused)
+    runner.prewarm_async(scap=16, local_only=False)
+    with pytest.raises(NotImplementedError, match="refused"):
+        runner.join_prewarm()
+    runner.join_prewarm()           # reported once; nothing left to raise
